@@ -81,6 +81,7 @@ import numpy as np
 from repro.configs.base import get_arch
 from repro.core.policy import get_policy
 from repro.core.qlinear import quantize_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.disagg import DisaggEngine
 from repro.serving.engine import Engine, ServeConfig
@@ -507,6 +508,7 @@ if __name__ == "__main__":
                          f"{SMOKE_DEPTHS} plus one spec run at depth "
                          f"{SPEC_SMOKE_DEPTHS[0]}")
     args = ap.parse_args()
+    enable_compile_cache()
     out = args.out
     if out is None:
         out = "" if args.smoke else "benchmarks/results/e2e_serve.json"

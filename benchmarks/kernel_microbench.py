@@ -14,6 +14,7 @@ from repro.core.quantize import quantize
 from repro.distributed.sharding import lane_shard_qtensor
 from repro.kernels import ops, ref
 from repro.kernels.bfp_matmul import bfp_matmul_pallas
+from repro.launch.compile_cache import enable_compile_cache
 from benchmarks.common import emit, time_jitted
 
 PEAK = 197e12
@@ -95,7 +96,9 @@ def run() -> None:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
-    if ap.parse_args().smoke:
+    args = ap.parse_args()
+    enable_compile_cache()
+    if args.smoke:
         smoke()
     else:
         run()

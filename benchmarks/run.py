@@ -9,6 +9,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig1_distribution, table2_kernel_resources,
                             table3_models, table4_speedup,
                             kernel_microbench, e2e_serve)

@@ -44,6 +44,7 @@ import numpy as np
 from repro.configs.base import get_arch
 from repro.core.policy import get_policy
 from repro.core.qlinear import quantize_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.engine import Engine, ServeConfig
 from benchmarks.common import emit, emit_json
@@ -261,6 +262,7 @@ if __name__ == "__main__":
                          f"{SMOKE_RATES} x {SMOKE_REQUESTS} requests "
                          "per mix")
     args = ap.parse_args()
+    enable_compile_cache()
     out = args.out
     if out is None:
         out = "" if args.smoke else "benchmarks/results/trace_serve.json"

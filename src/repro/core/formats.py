@@ -245,12 +245,15 @@ def slab_pack(q: jnp.ndarray, bits: int, sb_rows: int) -> jnp.ndarray:
 
 
 def slab_unpack(packed: jnp.ndarray, bits: int, sb_rows: int) -> jnp.ndarray:
-    """Inverse of slab_pack: (K//F, N) bytes -> (K, N) ints in [0, 2^bits)."""
+    """Inverse of slab_pack: (K//F, N) bytes -> (K, N) int32 in [0, 2^bits).
+
+    The bytes widen to int32 before any shift: Mosaic lowers 32-bit
+    vector shifts and int32 -> f32 casts, not their uint8 forms."""
     F = 8 // bits
     Kp, N = packed.shape
     slab = sb_rows // F
     assert Kp % slab == 0, (Kp, sb_rows)
-    p = packed.reshape(Kp // slab, slab, N)
+    p = packed.astype(jnp.int32).reshape(Kp // slab, slab, N)
     mask = (1 << bits) - 1
     slabs = [((p >> (bits * j)) & mask) for j in range(F)]
     return jnp.concatenate(slabs, axis=1).reshape(Kp * F, N)

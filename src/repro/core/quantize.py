@@ -77,6 +77,15 @@ def _safe_inv(x):
     return jnp.where(x > 0, 1.0 / jnp.where(x > 0, x, 1.0), 0.0)
 
 
+def _f32(a):
+    """Payload field -> f32. Integer fields widen through int32: Mosaic
+    (the Pallas TPU compiler) has no direct uint8 -> float32 cast, and the
+    fused kernel runs these same dequantize bodies on its VMEM tiles."""
+    if jnp.issubdtype(a.dtype, jnp.integer):
+        a = a.astype(jnp.int32)
+    return a.astype(jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Q2_K
 # ---------------------------------------------------------------------------
@@ -108,11 +117,12 @@ def quantize_q2_k(w: jnp.ndarray) -> QTensor:
 def dequantize_q2_k(t: QTensor, dtype=jnp.float32) -> jnp.ndarray:
     K, N = t.shape
     nsb = K // 256
-    q = slab_unpack(t.data["qs"], 2, 256).reshape(nsb, 16, 16, N).astype(jnp.float32)
-    sc = (t.data["scales"] & 0xF).reshape(nsb, 16, N).astype(jnp.float32)
-    mn = (t.data["scales"] >> 4).reshape(nsb, 16, N).astype(jnp.float32)
-    d = t.data["d"].astype(jnp.float32)[:, None]             # (sb, 1, N)
-    dmin = t.data["dmin"].astype(jnp.float32)[:, None]
+    q = _f32(slab_unpack(t.data["qs"], 2, 256)).reshape(nsb, 16, 16, N)
+    scales = t.data["scales"].astype(jnp.int32)
+    sc = _f32(scales & 0xF).reshape(nsb, 16, N)
+    mn = _f32(scales >> 4).reshape(nsb, 16, N)
+    d = _f32(t.data["d"])[:, None]                           # (sb, 1, N)
+    dmin = _f32(t.data["dmin"])[:, None]
     w = (d * sc)[:, :, None] * q - (dmin * mn)[:, :, None]
     return w.reshape(K, N).astype(dtype)
 
@@ -145,10 +155,10 @@ def dequantize_q3_k(t: QTensor, dtype=jnp.float32) -> jnp.ndarray:
     nsb = K // 256
     lo = slab_unpack(t.data["qs"], 2, 256)
     hi = slab_unpack(t.data["hmask"], 1, 256)
-    q = (lo + (hi << 2)).astype(jnp.float32) - 4.0           # [-4, 3]
+    q = _f32(lo + (hi << 2)) - 4.0                           # [-4, 3]
     q = q.reshape(nsb, 16, 16, N)
-    sc = t.data["scales"].astype(jnp.float32).reshape(nsb, 16, N) - 32.0
-    d = t.data["d"].astype(jnp.float32)[:, None]
+    sc = _f32(t.data["scales"]).reshape(nsb, 16, N) - 32.0
+    d = _f32(t.data["d"])[:, None]
     w = (d * sc)[:, :, None] * q
     return w.reshape(K, N).astype(dtype)
 
@@ -202,7 +212,7 @@ def dequantize_q3_k_o(t: QTensor, dtype=jnp.float32) -> jnp.ndarray:
                 {k: t.data[k] for k in ("qs", "hmask", "scales", "d")}),
         dtype=jnp.float32)
     idx = t.data["oidx"].astype(jnp.int32).reshape(nsb, no, N)
-    vals = t.data["ovals"].astype(jnp.float32).reshape(nsb, no, N)
+    vals = _f32(t.data["ovals"]).reshape(nsb, no, N)
     w = base.reshape(nsb, 256, N)
     rows = jax.lax.broadcasted_iota(jnp.int32, (nsb, 256, N), 1)
     # scatter-by-comparison: VPU-friendly inside the Pallas kernel (no
@@ -260,11 +270,11 @@ def _dequantize_q45_common(t, dtype):
     q = slab_unpack(t.data["qs"], 4, 256)
     if "qh" in t.data:
         q = q + (slab_unpack(t.data["qh"], 1, 256) << 4)
-    q = q.astype(jnp.float32).reshape(nsb, 8, 32, N)
-    sc = t.data["scales"].astype(jnp.float32).reshape(nsb, 8, N)
-    mn = t.data["mins"].astype(jnp.float32).reshape(nsb, 8, N)
-    d = t.data["d"].astype(jnp.float32)[:, None]
-    dmin = t.data["dmin"].astype(jnp.float32)[:, None]
+    q = _f32(q).reshape(nsb, 8, 32, N)
+    sc = _f32(t.data["scales"]).reshape(nsb, 8, N)
+    mn = _f32(t.data["mins"]).reshape(nsb, 8, N)
+    d = _f32(t.data["d"])[:, None]
+    dmin = _f32(t.data["dmin"])[:, None]
     w = (d * sc)[:, :, None] * q - (dmin * mn)[:, :, None]
     return w.reshape(K, N).astype(dtype)
 
@@ -303,11 +313,11 @@ def quantize_q6_k(w: jnp.ndarray) -> QTensor:
 def dequantize_q6_k(t: QTensor, dtype=jnp.float32) -> jnp.ndarray:
     K, N = t.shape
     nsb = K // 256
-    q = (slab_unpack(t.data["ql"], 4, 256)
-         + (slab_unpack(t.data["qh"], 2, 256) << 4)).astype(jnp.float32) - 32.0
+    q = _f32(slab_unpack(t.data["ql"], 4, 256)
+             + (slab_unpack(t.data["qh"], 2, 256) << 4)) - 32.0
     q = q.reshape(nsb, 16, 16, N)
-    sc = t.data["scales"].astype(jnp.float32).reshape(nsb, 16, N)
-    d = t.data["d"].astype(jnp.float32)[:, None]
+    sc = _f32(t.data["scales"]).reshape(nsb, 16, N)
+    d = _f32(t.data["d"])[:, None]
     w = (d * sc)[:, :, None] * q
     return w.reshape(K, N).astype(dtype)
 
@@ -335,8 +345,8 @@ def quantize_q4_0(w: jnp.ndarray) -> QTensor:
 
 def dequantize_q4_0(t: QTensor, dtype=jnp.float32) -> jnp.ndarray:
     K, N = t.shape
-    q = slab_unpack(t.data["qs"], 4, 32).astype(jnp.float32) - 8.0
-    d = t.data["d"].astype(jnp.float32)[:, None]             # (K//32, 1, N)
+    q = _f32(slab_unpack(t.data["qs"], 4, 32)) - 8.0
+    d = _f32(t.data["d"])[:, None]                           # (K//32, 1, N)
     w = d * q.reshape(K // 32, 32, N)
     return w.reshape(K, N).astype(dtype)
 
@@ -358,8 +368,8 @@ def quantize_q8_0(w: jnp.ndarray) -> QTensor:
 
 def dequantize_q8_0(t: QTensor, dtype=jnp.float32) -> jnp.ndarray:
     K, N = t.shape
-    q = t.data["qs"].astype(jnp.float32).reshape(K // 32, 32, N)
-    d = t.data["d"].astype(jnp.float32)[:, None]
+    q = _f32(t.data["qs"]).reshape(K // 32, 32, N)
+    d = _f32(t.data["d"])[:, None]
     return (d * q).reshape(K, N).astype(dtype)
 
 

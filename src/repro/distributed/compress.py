@@ -21,12 +21,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import dp_axes
 
-# jax.shard_map only exists as a top-level API in newer jax releases
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def compressed_psum(grads: Any, mesh: Mesh, wire_dtype=jnp.bfloat16,
                     error: Optional[Any] = None) -> Tuple[Any, Any]:
@@ -61,8 +55,8 @@ def compressed_psum(grads: Any, mesh: Mesh, wire_dtype=jnp.bfloat16,
         err = treedef.unflatten([t[1] for t in flat])
         return red, err
 
-    fn = _shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                    out_specs=(spec, spec))
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec))
     return fn(grads, error)
 
 

@@ -33,16 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.formats import get_format
 from repro.core.quantize import QTensor, dequantize
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept whichever this install provides
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version")
-
-
 def _choose_block_k(K: int, sb: int, target: int = 512) -> int:
     """Largest bk <= target with bk % sb == 0 and K % bk == 0.
 
@@ -68,9 +58,27 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _tile_rows(full: jnp.ndarray, start, rows: int) -> jnp.ndarray:
+    """Rows [start, start + rows) of a VMEM-resident whole-K payload block.
+
+    A payload with fewer than 8 rows per K tile (one value per super-block,
+    e.g. q2_k's d at bk 512 is 2 rows) has no legal tile of its own, so the
+    wrapper hands the kernel all of K and this picks the tile's rows by a
+    masked sublane reduction: Mosaic only slices dynamically at multiples
+    of 8 rows. Exact: every output row sums one selected value and zeros."""
+    R, bn = full.shape
+    shape = (rows, R, bn)
+    hit = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+           == jax.lax.broadcasted_iota(jnp.int32, shape, 0) + start)
+    return jnp.sum(jnp.where(hit, full[None], jnp.zeros((), full.dtype)),
+                   axis=1)
+
+
 def _kernel(x_ref, *rest, variant: str, names: Tuple[str, ...],
-            block_shape: Tuple[int, int], nk: int, compute_dtype):
-    """rest = (*weight_refs, out_ref)."""
+            whole_k: Tuple[int, ...], block_shape: Tuple[int, int],
+            compute_dtype):
+    """rest = (*weight_refs, out_ref). ``whole_k[i]`` is 0 for a payload
+    tiled along K, else its rows per K tile (its block holds all of K)."""
     w_refs, o_ref = rest[:-1], rest[-1]
     k = pl.program_id(2)
 
@@ -79,7 +87,14 @@ def _kernel(x_ref, *rest, variant: str, names: Tuple[str, ...],
         o_ref[...] = jnp.zeros_like(o_ref)
 
     # "bit-slicer + data mapper": unpack/dequantize this VMEM tile.
-    data = {name: ref[...] for name, ref in zip(names, w_refs)}
+    data = {}
+    for name, ref, rows in zip(names, w_refs, whole_k):
+        a = ref[...]
+        if rows:
+            if jnp.issubdtype(a.dtype, jnp.integer):
+                a = a.astype(jnp.int32)
+            a = _tile_rows(a, k * rows, rows)
+        data[name] = a
     qt = QTensor(variant, block_shape, data)
     w = dequantize(qt, dtype=compute_dtype)          # (bk, bn)
     x = x_ref[...].astype(compute_dtype)             # (bm, bk)
@@ -122,7 +137,11 @@ def bfp_matmul_pallas(x: jnp.ndarray, t: QTensor, *,
 
     if Mp != M:
         x = jnp.pad(x, ((0, Mp - M), (0, 0)))
-    data = dict(t.data)
+    # Mosaic cannot load f16 vectors: the f16 scale payloads (d/dmin, one
+    # row per super-block; q4_0/q8_0's d and q3_k_o's ovals, one per 32
+    # rows) widen to f32 here. The stored format stays f16.
+    data = {k2: v.astype(jnp.float32) if v.dtype == jnp.float16 else v
+            for k2, v in t.data.items()}
     if Np != N:
         data = {k2: jnp.pad(v, ((0, 0), (0, Np - N))) for k2, v in data.items()}
 
@@ -131,21 +150,28 @@ def bfp_matmul_pallas(x: jnp.ndarray, t: QTensor, *,
     grid = (Mp // bm, Np // bn, K // bk)
 
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))]
+    whole_k = []
     for name in names:
-        dv = kdiv[name]
-        in_specs.append(
-            pl.BlockSpec((bk // dv, bn),
-                         functools.partial(lambda i, j, k, _dv: (k, j), _dv=dv)))
+        rows = bk // kdiv[name]
+        if rows % 8 == 0 or rows == data[name].shape[0]:
+            whole_k.append(0)
+            in_specs.append(pl.BlockSpec((rows, bn), lambda i, j, k: (k, j)))
+        else:
+            # no legal (rows, bn) tile: hold all of K, which stays in VMEM
+            # across the K sweep (same block index), see _tile_rows
+            whole_k.append(rows)
+            in_specs.append(pl.BlockSpec((data[name].shape[0], bn),
+                                         lambda i, j, k: (0, j)))
 
     out = pl.pallas_call(
         functools.partial(_kernel, variant=t.variant, names=names,
-                          block_shape=(bk, bn), nk=grid[2],
+                          whole_k=tuple(whole_k), block_shape=(bk, bn),
                           compute_dtype=compute_dtype),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, *[data[n] for n in names])

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bfp_matmul import _CompilerParams, _round_up
+from repro.kernels.bfp_matmul import _round_up
 
 NEG_INF = -1e30
 
@@ -51,16 +51,16 @@ def _kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, m_ref, l_ref, *,
     q = q_ref[0].astype(jnp.float32)                 # (bq, D)
     k = k_ref[0].astype(jnp.float32)                 # (bk, D)
     v = v_ref[0].astype(jnp.float32)                 # (bk, D)
-    qp = qp_ref[0]                                   # (bq,) int32
-    kp = kp_ref[0]                                   # (bk,) int32
+    qp = qp_ref[0].reshape(-1, 1)                    # (bq, 1) int32
+    kp = kp_ref[0]                                   # (1, bk) int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
-    msk = (kp[None, :] >= 0) & (kp[None, :] <= qp[:, None])
+    msk = (kp >= 0) & (kp <= qp)
     if window:
-        msk &= kp[None, :] > qp[:, None] - window
+        msk &= kp > qp - window
     s = jnp.where(msk, s, NEG_INF)
 
     m_prev = m_ref[...]                              # (bq, 1)
@@ -114,6 +114,9 @@ def prefill_attn_fused(q, k, v, q_pos, kv_pos, *, window=None, scale=None,
         k2 = jnp.pad(k2, ((0, 0), (0, Tp - T), (0, 0)))
         v2 = jnp.pad(v2, ((0, 0), (0, Tp - T), (0, 0)))
         kp = jnp.pad(kp, ((0, 0), (0, Tp - T)), constant_values=-1)
+    # positions ride as (rows, 1, len): a (1, bq) block of a 2-D array is
+    # no legal tile, while the unit middle dim is the array's full extent
+    qp, kp = qp[:, None], kp[:, None]
 
     grid = (B * KH, Mp // bq, Tp // bk)
     out = pl.pallas_call(
@@ -124,14 +127,14 @@ def prefill_attn_fused(q, k, v, q_pos, kv_pos, *, window=None, scale=None,
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, i, j: (b, j)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KH, Mp, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, k2, v2, qp, kp)

@@ -9,12 +9,19 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int):
+    # jax.make_mesh defaults to Explicit axes; the sharding helpers
+    # constrain with bare PartitionSpecs, which need Auto axes
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_elastic_mesh(model_parallel: int = 1,
@@ -27,7 +34,7 @@ def make_elastic_mesh(model_parallel: int = 1,
     n = len(devices)
     mp = max(d for d in range(1, model_parallel + 1) if n % d == 0)
     return jax.make_mesh((n // mp, mp), ("data", "model"),
-                         devices=devices)
+                         devices=devices, axis_types=_auto(2))
 
 
 def validate_mesh(mesh, global_batch: int) -> None:

@@ -54,6 +54,7 @@ import numpy as np
 from repro.configs.base import get_arch
 from repro.core.policy import get_policy
 from repro.core.qlinear import quantize_params, quantized_param_bytes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.disagg import DisaggEngine
 from repro.serving.engine import Engine, ServeConfig
@@ -190,6 +191,7 @@ def main() -> None:
                          "seconds (overridable per request via "
                          "timeout_s)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, reduced=args.reduced)
     if not cfg.embed_input:

@@ -111,12 +111,6 @@ from repro.models.state import (DecodeState, KV_FAMILIES,
 from repro.serving.drafters import make_drafter
 from repro.serving.prefix_cache import PrefixCache
 
-# jax.shard_map only exists as a top-level API in newer jax releases
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 # re-export: the KV-ring family set now lives in the capability table
 # (models/state.py); serving/disagg.py and tests import it from here
 _KV_FAMILIES = KV_FAMILIES
@@ -432,9 +426,9 @@ class Engine:
                 return fn(params, *rest)
 
         return jax.jit(
-            _shard_map(body, mesh=self._mesh,
-                       in_specs=(pspecs,) + rest_in,
-                       out_specs=out_specs, check_rep=False),
+            jax.shard_map(body, mesh=self._mesh,
+                          in_specs=(pspecs,) + rest_in,
+                          out_specs=out_specs, check_vma=False),
             donate_argnums=donate)
 
     def _new_cache(self, B: int):

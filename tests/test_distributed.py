@@ -151,7 +151,8 @@ def test_constrain_noop_outside_context():
 def test_compressed_psum_error_feedback():
     """bf16-wire all-reduce with error feedback on a real 1-device mesh."""
     from repro.distributed.compress import compressed_psum
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jnp.asarray([[1.0004883, -2.0], [0.5, 3.141592]])}
     red, err = compressed_psum(g, mesh)
     # single device: reduced == bf16(g); error = g - bf16(g)
@@ -177,7 +178,8 @@ import jax
 # miniature production mesh pair: (4,4) and multi-pod (2,2,4)
 for axes, shape in ((("data","model"), (4,4)),
                     (("pod","data","model"), (2,2,4))):
-    mesh = jax.make_mesh(shape, axes)
+    mesh = jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     rec = D.dryrun_cell("llama3.2-1b", "decode_32k", mesh=mesh)
     assert rec["status"] == "ok", rec
     assert rec["memory"]["total_hbm_bytes"] > 0

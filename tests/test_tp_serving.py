@@ -445,7 +445,6 @@ def test_ring_collective_matmul_parity():
     activation-ulp contract, for a packed QTensor and a plain weight."""
     from jax.sharding import Mesh
     from repro.models import layers as L
-    from repro.serving.engine import _shard_map
     size = 2
     K, N, M = 512, 256, 8
     kx, kw = jax.random.split(jax.random.PRNGKey(3))
@@ -466,8 +465,9 @@ def test_ring_collective_matmul_parity():
                 size) if isinstance(wl, Q.QTensor) else wl
             with SH.serve_tp(plan):
                 return L.tp_ring_dense(xl, wl)
-        f = _shard_map(body, mesh=mesh, in_specs=(P(None, "model"), wspec),
-                       out_specs=P(), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(None, "model"), wspec),
+                          out_specs=P(), check_vma=False)
         got = np.asarray(jax.jit(f)(x, weight), np.float32)
         wf = Q.dequantize(t, dtype=jnp.bfloat16) if isinstance(
             weight, Q.QTensor) else w.astype(jnp.bfloat16)
