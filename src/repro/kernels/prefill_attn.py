@@ -132,6 +132,7 @@ def prefill_attn_fused(q, k, v, q_pos, kv_pos, *, window=None, scale=None,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KH, Mp, D), jnp.float32),
+        name="prefill_attn",
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(

@@ -77,6 +77,7 @@ def q8k_quantize_pallas(x: jnp.ndarray, *, valid: jnp.ndarray = None,
             jax.ShapeDtypeStruct((Mp, K // 256), jnp.float32),
             jax.ShapeDtypeStruct((Mp, K // 16), jnp.int16),
         ],
+        name="q8k_quantize",
         interpret=interpret,
     )(*args)
     return dict(qs=qs[:M], d=d[:M], bsums=bs[:M])

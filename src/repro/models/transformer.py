@@ -558,9 +558,10 @@ def _attn_layer_decode(h, lp, kc, vc, slot_pos, position, slot, cfg,
         vc = vc.at[bidx, slot].set(
             sel(v[:, 0].astype(vc.dtype), vc[bidx, slot], 2))
         k_eff, v_eff = kc, vc
-    o = L.decode_attention(q, k_eff, v_eff, slot_pos, position,
-                           window=cfg.sliding_window,
-                           softcap=cfg.attn_logit_softcap)
+    with jax.named_scope("decode_attn"):
+        o = L.decode_attention(q, k_eff, v_eff, slot_pos, position,
+                               window=cfg.sliding_window,
+                               softcap=cfg.attn_logit_softcap)
     h = h + _attn_out(o, lp, cfg, impl, interpret)
     m_in = L.norm(h, lp["ln2"], cfg.norm_type, cfg.norm_eps)
     if cfg.family == "moe":
@@ -621,14 +622,17 @@ def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any], *,
                                                          keepdims=False)
             upd = lambda a, x: jax.lax.dynamic_update_index_in_dim(a, x,
                                                                    li, 0)
-            ks = idx(ksall) if quant else None
-            vs = idx(vsall) if quant else None
+            with jax.named_scope("ring_read"):
+                kc, vc = idx(kall), idx(vall)
+                ks = idx(ksall) if quant else None
+                vs = idx(vsall) if quant else None
             hh, kc, vc, ks, vs = _attn_layer_decode(
-                hh, lp, idx(kall), idx(vall), slot_pos, position, slot,
+                hh, lp, kc, vc, slot_pos, position, slot,
                 cfg, cos_sin, impl, interpret, ks=ks, vs=vs, live=live)
-            kall, vall = upd(kall, kc), upd(vall, vc)
-            if quant:
-                ksall, vsall = upd(ksall, ks), upd(vsall, vs)
+            with jax.named_scope("ring_write"):
+                kall, vall = upd(kall, kc), upd(vall, vc)
+                if quant:
+                    ksall, vsall = upd(ksall, ks), upd(vsall, vs)
             return (hh, kall, vall, ksall, vsall), None
 
         zero = jnp.zeros((), jnp.float32)
@@ -670,7 +674,9 @@ def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any], *,
         raise ValueError(cfg.family)
 
     h = L.norm(h, params["ln_f"], cfg.norm_type, cfg.norm_eps)
-    logits = _logits(params, cfg, h[:, 0], impl=impl, interpret=interpret)
+    with jax.named_scope("lm_head"):
+        logits = _logits(params, cfg, h[:, 0], impl=impl,
+                         interpret=interpret)
     return logits, new_cache
 
 

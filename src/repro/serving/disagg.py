@@ -119,13 +119,20 @@ class DisaggEngine:
         radix activity is reported separately (it measures routing
         locality, not serving reuse)."""
         for k in ("prefill_s", "prefill_tokens", "prefill_groups",
-                  "host_syncs"):
+                  "host_syncs", "admit_prep_s", "admit_group_cache_s",
+                  "admit_prefill_s", "admit_bind_s", "admit_sync_s",
+                  "admit_emit_s", "prefill_padded_tokens",
+                  "queue_wait_total_s", "pre_enqueue_wait_s"):
             self.stats[k] += ws[k]
         if decode:
             for k in ("decode_s", "tokens", "chunks", "admissions",
                       "draft_tokens", "draft_accepted", "spec_rounds",
                       "prefix_hits", "prefix_tokens_reused",
-                      "prefix_evictions", "prefix_insert_drops"):
+                      "prefix_evictions", "prefix_insert_drops",
+                      "decode_dispatch_s", "decode_sync_s",
+                      "decode_emit_s", "decode_steps", "decode_slot_steps",
+                      "decode_tokens", "decode_ctx_positions",
+                      "decode_ring_positions", "poll_s"):
                 self.stats[k] += ws[k]
         else:
             self.stats["prefill_prefix_hits"] += ws["prefix_hits"]

@@ -244,6 +244,9 @@ class Request:
                                         # from (survives the disagg
                                         # prefill->decode hand-off via
                                         # submit(arrival_t=))
+    enqueue_t: Optional[float] = None   # perf_counter inside submit():
+                                        # when this engine got the request
+                                        # (after submit_t with arrival_t)
     ttft_s: Optional[float] = None      # first token - submit_t
     queue_wait_s: Optional[float] = None  # submit -> prefill start
 
@@ -251,6 +254,67 @@ class Request:
         self.tokens.append(tok)
         if self.on_token is not None:
             self.on_token(self.id, tok)
+
+
+class _Span:
+    """A named host span of the engine and its consecutive children.
+
+    The span and each child are ``jax.profiler.TraceAnnotation``s: the
+    profiler records them while it traces, on the clock of the device
+    planes, and otherwise they cost about a microsecond. ``phase`` ends
+    the open child and starts the next at ONE clock read and returns
+    that read, so children cover the span's extent without gaps. Each
+    child adds its duration to ``stats[<key>]``, where the key is its
+    name without ``engine.``, dots as underscores, plus ``_s``
+    (``engine.admit.sync`` -> ``admit_sync_s``); a span made with
+    ``timed=True`` adds its own duration the same way on exit."""
+
+    def __init__(self, stats: Dict[str, float], name: str,
+                 timed: bool = False):
+        self._stats, self._name, self._timed = stats, name, timed
+        self._child = None
+
+    @staticmethod
+    def _key(name: str) -> str:
+        return name[len("engine."):].replace(".", "_") + "_s"
+
+    @staticmethod
+    def recording() -> bool:
+        """True while the profiler traces: build span arguments only
+        then. An id list renders as "[1, 2]" (the trace reader takes a
+        bare "1,2" for the number 1)."""
+        return jax.profiler.TraceAnnotation.is_enabled()
+
+    def args(self, **kw) -> None:
+        self._ann.set_metadata(**kw)
+
+    def __enter__(self) -> "_Span":
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = self._t = time.perf_counter()
+        return self
+
+    def phase(self, child: Optional[str] = None) -> float:
+        """End the open child, start ``<name>.<child>`` (none when
+        ``child`` is None); returns the clock read between the two."""
+        now = time.perf_counter()
+        if self._child is not None:
+            ann, key = self._child
+            ann.__exit__(None, None, None)
+            self._stats[key] += now - self._t
+        self._t, self._child = now, None
+        if child is not None:
+            name = f"{self._name}.{child}"
+            ann = jax.profiler.TraceAnnotation(name)
+            ann.__enter__()
+            self._child = (ann, self._key(name))
+        return now
+
+    def __exit__(self, *exc) -> None:
+        now = self.phase()
+        if self._timed:
+            self._stats[self._key(self._name)] += now - self._t0
+        self._ann.__exit__(*exc)
 
 
 class Engine:
@@ -569,9 +633,10 @@ class Engine:
             logits, cache_ = T.decode_step(params, self.cfg, cache_,
                                            tokens=tok_, position=pos_,
                                            live=live_)
-            key_, sub = jax.random.split(key_)
-            nxt = self._sample(logits, sub)
-            nxt = jnp.where(live_, nxt, tok_)
+            with jax.named_scope("sample"):
+                key_, sub = jax.random.split(key_)
+                nxt = self._sample(logits, sub)
+                nxt = jnp.where(live_, nxt, tok_)
             out_ = out_.at[:, step].set(jnp.where(live_, nxt, -1))
             n_gen_ = n_gen_ + live_.astype(jnp.int32)
             new_live = live_ & (n_gen_ < budget)
@@ -781,7 +846,20 @@ class Engine:
                     deadline_misses=0, preemptions=0,
                     draft_tokens=0, draft_accepted=0, accept_rate=0.0,
                     spec_rounds=0, prefix_hits=0, prefix_tokens_reused=0,
-                    prefix_evictions=0, prefix_insert_drops=0)
+                    prefix_evictions=0, prefix_insert_drops=0,
+                    # span seconds (_Span): admit_{prep,group_cache,
+                    # prefill,bind,sync}_s partition prefill_s and
+                    # decode_{dispatch,sync}_s partition decode_s
+                    poll_s=0.0, admit_prep_s=0.0, admit_group_cache_s=0.0,
+                    admit_prefill_s=0.0, admit_bind_s=0.0,
+                    admit_sync_s=0.0, admit_emit_s=0.0,
+                    decode_dispatch_s=0.0, decode_sync_s=0.0,
+                    decode_emit_s=0.0,
+                    # work counters: useful work beside what the device ran
+                    decode_steps=0, decode_slot_steps=0, decode_tokens=0,
+                    decode_ctx_positions=0, decode_ring_positions=0,
+                    prefill_padded_tokens=0,
+                    queue_wait_total_s=0.0, pre_enqueue_wait_s=0.0)
 
     def submit(self, prompt: List[int],
                max_new_tokens: Optional[int] = None,
@@ -841,12 +919,13 @@ class Engine:
                         f"queued prompts need {demand} KV pages, pool "
                         f"capacity is {self._prefix.capacity} "
                         "(raise ServeConfig.prefix_bytes or shed load)")
+        now = time.perf_counter()
         req = Request(id=self._next_id, prompt=list(prompt),
                       max_new_tokens=budget, on_token=on_token,
                       speculate=speculate, priority=int(priority),
                       deadline_s=deadline_s, on_done=on_done,
-                      submit_t=(time.perf_counter() if arrival_t is None
-                                else arrival_t))
+                      submit_t=now if arrival_t is None else arrival_t,
+                      enqueue_t=now)
         self._next_id += 1
         self._queue.append(req)
         return req.id
@@ -1264,112 +1343,128 @@ class Engine:
         below it), the chunk loop starts at s0, and freshly recorded
         pages capture their checkpoints chunk by chunk (the inter-chunk
         carry, copied before the next chunk donates it)."""
-        t0 = time.perf_counter()
-        for r in reqs:
-            if r.submit_t is not None:
-                r.queue_wait_s = t0 - r.submit_t
-        G = len(reqs)
-        lens = [len(r.prompt) for r in reqs]
-        pjobs: List = []
-        ckpt_jobs: List = []
-        ins_by_chunk: Dict[int, List] = {}
-        kv_ins_jobs: List = []
-        if self._prefix is None:
-            matches, s0 = [0] * G, 0
-        elif self._kv_family:
-            matches, pjobs = self._match_prefixes(reqs)
-            s0 = min(matches)
-        else:
-            # recurrent: whole-state checkpoints, full pages only, one
-            # shared horizon; the per-column cached mask stays 0 (the
-            # restored checkpoint replaces masking -- the chunk GRID
-            # starts at s0 instead)
-            s0, fulls, pjobs, ckpt_jobs = self._match_checkpoints(reqs)
-            matches = [0] * G
-            ins_by_chunk, kv_ins_jobs = self._plan_checkpoint_inserts(
-                reqs, lens, fulls, s0)
-        P, C, Gp = self._group_shape([n - s0 for n in lens])
-        toks = np.zeros((Gp, s0 + P), np.int32)
-        lengths = np.zeros(Gp, np.int32)            # dummy rows: length 0
-        cached = np.zeros(Gp, np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, :lens[i]] = r.prompt
-            lengths[i] = lens[i]
-            cached[i] = matches[i]
-        # split one key per request IN QUEUE ORDER -- exactly the stream a
-        # sequential (prefill_batch=1) admission loop would consume, so the
-        # two schedules sample identical first tokens
-        subs = []
-        for _ in range(G):
-            self._key, sub = jax.random.split(self._key)
-            subs.append(sub)
-        subs += [subs[-1]] * (Gp - G)               # dummies: never emitted
-        if self._cache is None:
-            self._cache = self._new_cache(self._B)
-        gcache = self._new_cache(Gp)
-        if ckpt_jobs:
-            gcache = self._scatter_checkpoints(gcache, ckpt_jobs, Gp)
-        if pjobs:
-            gcache = self._scatter_prefix_pages(gcache, pjobs)
-        last_logits = jnp.zeros((Gp, self.cfg.vocab_size), jnp.float32)
-        lengths_d = jnp.asarray(lengths)
-        cached_d = jnp.asarray(cached)
-        for j in range(P // C):
-            start = s0 + j * C
-            gcache, last_logits = self._prefill_chunk(
-                self.params, gcache, jnp.asarray(toks[:, start:start + C]),
-                jnp.asarray(start, jnp.int32), lengths_d, last_logits,
-                cached_d)
-            if j in ins_by_chunk:
-                # checkpoint copies ride the device queue here, BEFORE
-                # the next chunk call donates (and so invalidates) the
-                # group-cache buffers they read from
-                self._insert_checkpoints(gcache, ins_by_chunk[j])
-        first_d = self._sample_first(last_logits, jnp.stack(subs))
-        budgets = np.zeros(Gp, np.int32)            # dummies: 0 -> unbound
-        budgets[:G] = [r.max_new_tokens for r in reqs]
-        # free list padded to Gp so compiled shapes track the group
-        # BUCKET, not the exact group size (pad entries are never read:
-        # survivor ranks stay < G)
-        free_arr = np.full(Gp, self._B, np.int32)
-        free_arr[:G] = slots
-        idx_d = self._bind_slots(first_d, jnp.asarray(budgets),
-                                 jnp.asarray(free_arr))
-        self._cache = self._admit_caches(self._cache, gcache, idx_d)
-        if self._prefix is not None:
-            if self._kv_family:
-                # record this group's prompt pages (async dispatch, rides
-                # the same device queue -- admission stays one host sync)
-                self._insert_prefix_pages(gcache, reqs, lens)
-            elif kv_ins_jobs:
-                # hybrid: ring payload of the pages recorded pre-loop
-                self._insert_ring_pages(gcache, kv_ins_jobs)
-        firsts = np.asarray(jax.device_get(first_d))   # 1 sync / GROUP
-        # host-side mirror of _bind_slots_impl for the bookkeeping below
-        free_iter = iter(slots)
-        bound = [None if (req.max_new_tokens <= 1
-                          or (self.scfg.eos_id is not None
-                              and int(firsts[i]) == self.scfg.eos_id))
-                 else next(free_iter) for i, req in enumerate(reqs)]
-        self.stats["host_syncs"] += 1
-        self.stats["prefill_groups"] += 1
-        self.stats["admissions"] += G
-        self.stats["prefill_tokens"] += sum(lens)
-        self.stats["prefill_s"] += time.perf_counter() - t0
-        self._admitting = reqs
-        for i, req in enumerate(reqs):
-            if req.cancelled:
-                # cancelled from a group-mate's on_token callback after
-                # its prefill but before its slot bound: never binds,
-                # never emits (its scattered cache row is inert garbage)
-                continue
-            if bound[i] is None:
-                self._note_first_token(req)
-                req._emit(int(firsts[i]))
-                self._finish(req)
+        with _Span(self.stats, "engine.admit") as span:
+            t0 = span.phase("prep")
+            for r in reqs:
+                if r.submit_t is not None:
+                    r.queue_wait_s = t0 - r.submit_t
+                    self.stats["queue_wait_total_s"] += r.queue_wait_s
+                    self.stats["pre_enqueue_wait_s"] += (r.enqueue_t
+                                                         - r.submit_t)
+            G = len(reqs)
+            lens = [len(r.prompt) for r in reqs]
+            pjobs: List = []
+            ckpt_jobs: List = []
+            ins_by_chunk: Dict[int, List] = {}
+            kv_ins_jobs: List = []
+            if self._prefix is None:
+                matches, s0 = [0] * G, 0
+            elif self._kv_family:
+                matches, pjobs = self._match_prefixes(reqs)
+                s0 = min(matches)
             else:
-                self._start_slot(bound[i], req, int(firsts[i]), lens[i])
-        self._admitting = []
+                # recurrent: whole-state checkpoints, full pages only, one
+                # shared horizon; the per-column cached mask stays 0 (the
+                # restored checkpoint replaces masking -- the chunk GRID
+                # starts at s0 instead)
+                s0, fulls, pjobs, ckpt_jobs = self._match_checkpoints(reqs)
+                matches = [0] * G
+                ins_by_chunk, kv_ins_jobs = self._plan_checkpoint_inserts(
+                    reqs, lens, fulls, s0)
+            P, C, Gp = self._group_shape([n - s0 for n in lens])
+            if span.recording():
+                span.args(G=G, Gp=Gp, P=P, C=C, s0=s0,
+                          rids=str([r.id for r in reqs]))
+            toks = np.zeros((Gp, s0 + P), np.int32)
+            lengths = np.zeros(Gp, np.int32)        # dummy rows: length 0
+            cached = np.zeros(Gp, np.int32)
+            for i, r in enumerate(reqs):
+                toks[i, :lens[i]] = r.prompt
+                lengths[i] = lens[i]
+                cached[i] = matches[i]
+            # split one key per request IN QUEUE ORDER -- exactly the
+            # stream a sequential (prefill_batch=1) admission loop would
+            # consume, so the two schedules sample identical first tokens
+            subs = []
+            for _ in range(G):
+                self._key, sub = jax.random.split(self._key)
+                subs.append(sub)
+            subs += [subs[-1]] * (Gp - G)           # dummies: never emitted
+            if self._cache is None:
+                self._cache = self._new_cache(self._B)
+            span.phase("group_cache")
+            gcache = self._new_cache(Gp)
+            if ckpt_jobs:
+                gcache = self._scatter_checkpoints(gcache, ckpt_jobs, Gp)
+            if pjobs:
+                gcache = self._scatter_prefix_pages(gcache, pjobs)
+            span.phase("prefill")
+            last_logits = jnp.zeros((Gp, self.cfg.vocab_size), jnp.float32)
+            lengths_d = jnp.asarray(lengths)
+            cached_d = jnp.asarray(cached)
+            for j in range(P // C):
+                start = s0 + j * C
+                gcache, last_logits = self._prefill_chunk(
+                    self.params, gcache,
+                    jnp.asarray(toks[:, start:start + C]),
+                    jnp.asarray(start, jnp.int32), lengths_d, last_logits,
+                    cached_d)
+                if j in ins_by_chunk:
+                    # checkpoint copies ride the device queue here, BEFORE
+                    # the next chunk call donates (and so invalidates) the
+                    # group-cache buffers they read from
+                    self._insert_checkpoints(gcache, ins_by_chunk[j])
+            span.phase("bind")
+            first_d = self._sample_first(last_logits, jnp.stack(subs))
+            budgets = np.zeros(Gp, np.int32)        # dummies: 0 -> unbound
+            budgets[:G] = [r.max_new_tokens for r in reqs]
+            # free list padded to Gp so compiled shapes track the group
+            # BUCKET, not the exact group size (pad entries are never
+            # read: survivor ranks stay < G)
+            free_arr = np.full(Gp, self._B, np.int32)
+            free_arr[:G] = slots
+            idx_d = self._bind_slots(first_d, jnp.asarray(budgets),
+                                     jnp.asarray(free_arr))
+            self._cache = self._admit_caches(self._cache, gcache, idx_d)
+            if self._prefix is not None:
+                if self._kv_family:
+                    # record this group's prompt pages (async dispatch,
+                    # rides the same device queue -- admission stays one
+                    # host sync)
+                    self._insert_prefix_pages(gcache, reqs, lens)
+                elif kv_ins_jobs:
+                    # hybrid: ring payload of the pages recorded pre-loop
+                    self._insert_ring_pages(gcache, kv_ins_jobs)
+            span.phase("sync")
+            firsts = np.asarray(jax.device_get(first_d))   # 1 sync / GROUP
+            # host-side mirror of _bind_slots_impl for the bookkeeping
+            free_iter = iter(slots)
+            bound = [None if (req.max_new_tokens <= 1
+                              or (self.scfg.eos_id is not None
+                                  and int(firsts[i]) == self.scfg.eos_id))
+                     else next(free_iter) for i, req in enumerate(reqs)]
+            self.stats["host_syncs"] += 1
+            self.stats["prefill_groups"] += 1
+            self.stats["admissions"] += G
+            self.stats["prefill_tokens"] += sum(lens)
+            self.stats["prefill_padded_tokens"] += Gp * (s0 + P)
+            self.stats["prefill_s"] += span.phase("emit") - t0
+            self._admitting = reqs
+            for i, req in enumerate(reqs):
+                if req.cancelled:
+                    # cancelled from a group-mate's on_token callback
+                    # after its prefill but before its slot bound: never
+                    # binds, never emits (its scattered cache row is
+                    # inert garbage)
+                    continue
+                if bound[i] is None:
+                    self._note_first_token(req)
+                    req._emit(int(firsts[i]))
+                    self._finish(req)
+                else:
+                    self._start_slot(bound[i], req, int(firsts[i]),
+                                     lens[i])
+            self._admitting = []
 
     @staticmethod
     def _admit_key(req: Request):
@@ -1427,39 +1522,68 @@ class Engine:
             self._admit_group(free[:n], self._pop_pending(n))
 
     def _run_chunk(self) -> None:
-        t0 = time.perf_counter()
-        if self._drafter is not None:
-            dstate_d = {k: jnp.asarray(v) for k, v in self._dstate.items()}
-            (self._cache, out_d, tok_d, pos_d, live_d, ngen_d, self._key,
-             ds_d, dtot_d, dacc_d, rounds_d) = self._spec_chunk(
-                self.params, self._cache, jnp.asarray(self._tok),
-                jnp.asarray(self._pos), jnp.asarray(self._live),
-                jnp.asarray(self._spec), jnp.asarray(self._ngen),
-                jnp.asarray(self._budget), self._key, dstate_d)
-            out, tok, pos, live, ngen, ds, dtot, dacc, rounds = \
-                jax.device_get((out_d, tok_d, pos_d, live_d, ngen_d, ds_d,
-                                dtot_d, dacc_d, rounds_d))  # THE sync
-            self._dstate = {k: np.array(v) for k, v in ds.items()}
-            self.stats["draft_tokens"] += int(dtot)
-            self.stats["draft_accepted"] += int(dacc)
-            self.stats["spec_rounds"] += int(rounds)
-        else:
-            self._cache, out_d, tok_d, pos_d, live_d, ngen_d, self._key = \
-                self._decode_chunk(self.params, self._cache,
-                                   jnp.asarray(self._tok),
-                                   jnp.asarray(self._pos),
-                                   jnp.asarray(self._live),
-                                   jnp.asarray(self._ngen),
-                                   jnp.asarray(self._budget), self._key)
-            out, tok, pos, live, ngen = jax.device_get(
-                (out_d, tok_d, pos_d, live_d, ngen_d))  # THE chunk sync
-        # device_get hands back read-only buffers; admission mutates these
-        self._tok, self._pos = np.array(tok), np.array(pos)
-        self._live, self._ngen = np.array(live), np.array(ngen)
-        self.stats["host_syncs"] += 1
-        self.stats["chunks"] += 1
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self._emit_chunk(out)
+        with _Span(self.stats, "engine.decode") as span:
+            if span.recording():
+                rids = [r.id for i, r in enumerate(self._slots)
+                        if r is not None and self._live[i]]
+                span.args(live=len(rids), rids=str(rids))
+            pos0 = self._pos
+            t0 = span.phase("dispatch")
+            if self._drafter is not None:
+                dstate_d = {k: jnp.asarray(v)
+                            for k, v in self._dstate.items()}
+                (self._cache, out_d, tok_d, pos_d, live_d, ngen_d,
+                 self._key, ds_d, dtot_d, dacc_d, rounds_d) = \
+                    self._spec_chunk(
+                        self.params, self._cache, jnp.asarray(self._tok),
+                        jnp.asarray(self._pos), jnp.asarray(self._live),
+                        jnp.asarray(self._spec), jnp.asarray(self._ngen),
+                        jnp.asarray(self._budget), self._key, dstate_d)
+                span.phase("sync")
+                out, tok, pos, live, ngen, ds, dtot, dacc, rounds = \
+                    jax.device_get((out_d, tok_d, pos_d, live_d, ngen_d,
+                                    ds_d, dtot_d, dacc_d,
+                                    rounds_d))          # THE sync
+                self._dstate = {k: np.array(v) for k, v in ds.items()}
+                self.stats["draft_tokens"] += int(dtot)
+                self.stats["draft_accepted"] += int(dacc)
+                self.stats["spec_rounds"] += int(rounds)
+            else:
+                (self._cache, out_d, tok_d, pos_d, live_d, ngen_d,
+                 self._key) = self._decode_chunk(
+                    self.params, self._cache, jnp.asarray(self._tok),
+                    jnp.asarray(self._pos), jnp.asarray(self._live),
+                    jnp.asarray(self._ngen), jnp.asarray(self._budget),
+                    self._key)
+                span.phase("sync")
+                out, tok, pos, live, ngen = jax.device_get(
+                    (out_d, tok_d, pos_d, live_d, ngen_d))  # THE sync
+            # device_get hands back read-only buffers; admission mutates
+            # these
+            self._tok, self._pos = np.array(tok), np.array(pos)
+            self._live, self._ngen = np.array(live), np.array(ngen)
+            self.stats["host_syncs"] += 1
+            self.stats["chunks"] += 1
+            self._count_chunk(out, pos0)
+            self.stats["decode_s"] += span.phase("emit") - t0
+            self._emit_chunk(out)
+
+    def _count_chunk(self, out: np.ndarray, pos0: np.ndarray) -> None:
+        """Useful decode work beside what the device ran. The chunk ran
+        as many steps as its busiest slot emitted tokens, each over all
+        ``max_slots`` slots and the whole ring; a slot at position p that
+        emitted k tokens attended k*p + k(k-1)/2 live positions (each
+        step capped at the ring length)."""
+        n = (out >= 0).sum(axis=1)
+        steps = int(n.max())
+        j = np.arange(out.shape[1])
+        ctx = np.minimum(pos0[:, None] + j, self._T) * (j < n[:, None])
+        s = self.stats
+        s["decode_steps"] += steps
+        s["decode_slot_steps"] += steps * self._B
+        s["decode_tokens"] += int(n.sum())
+        s["decode_ctx_positions"] += int(ctx.sum())
+        s["decode_ring_positions"] += steps * self._B * self._T
 
     def _emit_chunk(self, out: np.ndarray) -> None:
         """Stream each slot's dense token prefix; free finished slots."""
@@ -1530,7 +1654,8 @@ class Engine:
         self._run_t0 = time.perf_counter()
         while True:
             if poll is not None:
-                poll()
+                with _Span(self.stats, "engine.poll", timed=True):
+                    poll()
             if not (self._queue or any(r is not None
                                        for r in self._slots)):
                 break

@@ -342,3 +342,34 @@ def test_disagg_cancel_queued(causal):
     res = dis.run()
     assert res[rid] == []
     assert len(res[keep]) == _BASE["max_new_tokens"]
+
+
+def test_disagg_folds_span_and_work_counters(causal):
+    """The engine's span seconds and work counters reach the aggregate:
+    prefill-side keys from both tiers, decode-side keys from the decode
+    workers alone (one wave, so each worker's stats are that wave's)."""
+    cfg, params = causal
+    dis = DisaggEngine(cfg, params, _scfg(),
+                       prefill_workers=1, decode_workers=1)
+    outs = dis.generate(_prompts(cfg, 3, seed=5))
+    s = dis.stats
+    tiers = dis.prefill_engines + dis.decode_engines
+    for k in ("admit_prep_s", "admit_group_cache_s", "admit_prefill_s",
+              "admit_bind_s", "admit_sync_s", "admit_emit_s",
+              "prefill_padded_tokens", "queue_wait_total_s",
+              "pre_enqueue_wait_s"):
+        assert s[k] == pytest.approx(sum(e.stats[k] for e in tiers)), k
+    for k in ("decode_dispatch_s", "decode_sync_s", "decode_emit_s",
+              "decode_steps", "decode_slot_steps", "decode_tokens",
+              "decode_ctx_positions", "decode_ring_positions", "poll_s"):
+        assert s[k] == pytest.approx(
+            sum(e.stats[k] for e in dis.decode_engines)), k
+    assert s["prefill_padded_tokens"] > dis.prefill_engines[0].stats[
+        "prefill_padded_tokens"] > 0
+    assert s["decode_tokens"] == sum(len(o) - 1 for o in outs)
+    assert s["decode_ring_positions"] > 0 and s["queue_wait_total_s"] > 0
+    assert s["prefill_s"] == pytest.approx(sum(
+        s[f"admit_{k}_s"] for k in ("prep", "group_cache", "prefill",
+                                    "bind", "sync")), abs=1e-6)
+    assert s["decode_s"] == pytest.approx(
+        s["decode_dispatch_s"] + s["decode_sync_s"], abs=1e-6)
