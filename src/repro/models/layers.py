@@ -12,7 +12,8 @@ Attention implementations:
     chunks (static per Q chunk), so HLO FLOPs stay ~triangular and peak
     memory is one (cq x ck) score block. This is the dry-run/long-seq path.
   * decode         -- single-token query against a (possibly ring-buffer)
-    cache with per-slot absolute positions.
+    cache with per-slot absolute positions; ``decode_attention_ring``
+    reads one layer of the stacked ring in place (a Pallas kernel on TPU).
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ from repro.core import calibrate as CAL
 from repro.core.quantize import QTensor, dequantize
 from repro.distributed.sharding import constrain, serve_tp_plan
 from repro.kernels import ops as kops
+from repro.kernels.decode_attn import decode_attn
 from repro.kernels.prefill_attn import prefill_attn_fused
 
 NEG_INF = -1e30
@@ -324,6 +326,26 @@ def decode_attention(q, k_cache, v_cache, slot_pos, q_pos, *,
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,btkd->bkgd", p, v_cache.astype(jnp.float32))
     return o.reshape(B, 1, H, D).astype(q.dtype)
+
+
+def decode_attention_ring(q, k_ring, v_ring, layer, slot_pos, q_pos, *,
+                          window=None, softcap=None):
+    """``decode_attention`` against layer ``layer`` of the stacked
+    (L, B, T, KH, D) rings. Lowered for a TPU, a bf16 / f32 ring is read
+    in place by the ``decode_attn`` Pallas kernel; elsewhere the layer is
+    sliced out and ``decode_attention`` (the reference) runs on it."""
+    def xla():
+        at = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                    keepdims=False)
+        return decode_attention(q, at(k_ring), at(v_ring), slot_pos, q_pos,
+                                window=window, softcap=softcap)
+
+    if k_ring.dtype not in (jnp.bfloat16, jnp.float32):
+        return xla()
+    return jax.lax.platform_dependent(
+        tpu=lambda: decode_attn(q, k_ring, v_ring, layer, slot_pos, q_pos,
+                                window=window, softcap=softcap),
+        default=xla)
 
 
 # ---------------------------------------------------------------------------

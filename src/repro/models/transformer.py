@@ -522,13 +522,13 @@ def _quantize_kv(x):
     return q, scale
 
 
-def _attn_layer_decode(h, lp, kc, vc, slot_pos, position, slot, cfg,
-                       cos_sin, impl, interpret, ks=None, vs=None,
-                       live=None):
-    """h: (B,1,d); kc/vc: (B,T,KH,Dh); position/slot: (B,).
-    ks/vs: (B,T,KH) int8-cache scales when cfg.kv_cache_quant.
-    live: (B,) bool -- dead slots leave the cache untouched (their logits
-    are garbage and must be ignored by the caller)."""
+def _attn_layer_decode(h, lp, li, kall, vall, ksall, vsall, slot_pos,
+                       position, slot_w, cfg, cos_sin, impl, interpret):
+    """h: (B,1,d); kall/vall: (L,B,T,KH,Dh) stacked rings, this layer at
+    ``li``; position/slot_w: (B,). ksall/vsall: (L,B,T,KH) int8-ring
+    scales when cfg.kv_cache_quant. The new token's K/V row is written in
+    place at (li, b, slot_w[b]); a slot_w of T (a dead slot) drops the
+    write, so the ring is never copied and dead slots keep theirs."""
     B = h.shape[0]
     a_in = L.norm(h, lp["ln1"], cfg.norm_type, cfg.norm_eps)
     q, k, v = _qkv(a_in, lp, cfg, impl, interpret)
@@ -537,31 +537,30 @@ def _attn_layer_decode(h, lp, kc, vc, slot_pos, position, slot, cfg,
         q = L.apply_rope(q, cos, sin)
         k = L.apply_rope(k, cos, sin)
     bidx = jnp.arange(B)
-
-    def sel(new, old, extra_dims):
-        if live is None:
-            return new
-        return jnp.where(live.reshape((B,) + (1,) * extra_dims), new, old)
-
-    if cfg.kv_cache_quant:
-        kq, kscale = _quantize_kv(k[:, 0])
-        vq, vscale = _quantize_kv(v[:, 0])
-        kc = kc.at[bidx, slot].set(sel(kq, kc[bidx, slot], 2))
-        vc = vc.at[bidx, slot].set(sel(vq, vc[bidx, slot], 2))
-        ks = ks.at[bidx, slot].set(sel(kscale, ks[bidx, slot], 1))
-        vs = vs.at[bidx, slot].set(sel(vscale, vs[bidx, slot], 1))
-        k_eff = kc.astype(jnp.float32) * ks[..., None]
-        v_eff = vc.astype(jnp.float32) * vs[..., None]
-    else:
-        kc = kc.at[bidx, slot].set(
-            sel(k[:, 0].astype(kc.dtype), kc[bidx, slot], 2))
-        vc = vc.at[bidx, slot].set(
-            sel(v[:, 0].astype(vc.dtype), vc[bidx, slot], 2))
-        k_eff, v_eff = kc, vc
+    put = lambda a, x: a.at[li, bidx, slot_w].set(x, mode="drop")
+    with jax.named_scope("ring_write"):
+        if cfg.kv_cache_quant:
+            kq, kscale = _quantize_kv(k[:, 0])
+            vq, vscale = _quantize_kv(v[:, 0])
+            kall, vall = put(kall, kq), put(vall, vq)
+            ksall, vsall = put(ksall, kscale), put(vsall, vscale)
+        else:
+            kall = put(kall, k[:, 0].astype(kall.dtype))
+            vall = put(vall, v[:, 0].astype(vall.dtype))
     with jax.named_scope("decode_attn"):
-        o = L.decode_attention(q, k_eff, v_eff, slot_pos, position,
-                               window=cfg.sliding_window,
-                               softcap=cfg.attn_logit_softcap)
+        if cfg.kv_cache_quant:
+            # the int8 ring is dequantized whole and read by XLA
+            at = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
+                                                        keepdims=False)
+            k_eff = at(kall).astype(jnp.float32) * at(ksall)[..., None]
+            v_eff = at(vall).astype(jnp.float32) * at(vsall)[..., None]
+            o = L.decode_attention(q, k_eff, v_eff, slot_pos, position,
+                                   window=cfg.sliding_window,
+                                   softcap=cfg.attn_logit_softcap)
+        else:
+            o = L.decode_attention_ring(q, kall, vall, li, slot_pos,
+                                        position, window=cfg.sliding_window,
+                                        softcap=cfg.attn_logit_softcap)
     h = h + _attn_out(o, lp, cfg, impl, interpret)
     m_in = L.norm(h, lp["ln2"], cfg.norm_type, cfg.norm_eps)
     if cfg.family == "moe":
@@ -572,7 +571,7 @@ def _attn_layer_decode(h, lp, kc, vc, slot_pos, position, slot, cfg,
         h = h + L.gelu_mlp(m_in, lp["mlp"], impl=impl, interpret=interpret)
     else:
         h = h + L.swiglu_mlp(m_in, lp["mlp"], impl=impl, interpret=interpret)
-    return h, kc, vc, ks, vs
+    return h, kall, vall, ksall, vsall
 
 
 def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any], *,
@@ -605,35 +604,21 @@ def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any], *,
     lidx = jnp.arange(Lc)
     if cfg.family in ("dense", "vlm", "audio", "moe", "gpt2"):
         T = cache["k"].shape[2]
-        slot = position % T
-        pos_new = position if live is None else jnp.where(
-            live, position, cache["pos"][jnp.arange(B), slot])
-        slot_pos = cache["pos"].at[jnp.arange(B), slot].set(pos_new)
+        slot_w = position % T
+        if live is not None:            # T is out of range: the write drops
+            slot_w = jnp.where(live, slot_w, T)
+        slot_pos = cache["pos"].at[jnp.arange(B), slot_w].set(
+            position, mode="drop")
         new_cache["pos"] = slot_pos
 
-        quant = cfg.kv_cache_quant
-
-        # caches ride the scan *carry* and are updated in place with
-        # dynamic_update_index so XLA can alias the buffers step-to-step
+        # the stacked rings ride the scan carry; each layer writes its new
+        # rows into them in place and attention reads its layer where it
+        # lies, so no per-layer ring is sliced out or written back
         def body(carry, xs):
-            hh, kall, vall, ksall, vsall = carry
             lp, li = xs
-            idx = lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
-                                                         keepdims=False)
-            upd = lambda a, x: jax.lax.dynamic_update_index_in_dim(a, x,
-                                                                   li, 0)
-            with jax.named_scope("ring_read"):
-                kc, vc = idx(kall), idx(vall)
-                ks = idx(ksall) if quant else None
-                vs = idx(vsall) if quant else None
-            hh, kc, vc, ks, vs = _attn_layer_decode(
-                hh, lp, kc, vc, slot_pos, position, slot,
-                cfg, cos_sin, impl, interpret, ks=ks, vs=vs, live=live)
-            with jax.named_scope("ring_write"):
-                kall, vall = upd(kall, kc), upd(vall, vc)
-                if quant:
-                    ksall, vsall = upd(ksall, ks), upd(vsall, vs)
-            return (hh, kall, vall, ksall, vsall), None
+            return _attn_layer_decode(carry[0], lp, li, *carry[1:], slot_pos,
+                                      position, slot_w, cfg, cos_sin, impl,
+                                      interpret), None
 
         zero = jnp.zeros((), jnp.float32)
         (h, knew, vnew, ksnew, vsnew), _ = jax.lax.scan(
@@ -641,7 +626,7 @@ def decode_step(params, cfg: ModelConfig, cache: Dict[str, Any], *,
                    cache.get("k_scale", zero), cache.get("v_scale", zero)),
             (params["layers"], lidx), unroll=_unroll(cfg))
         new_cache["k"], new_cache["v"] = knew, vnew
-        if quant:
+        if cfg.kv_cache_quant:
             new_cache["k_scale"], new_cache["v_scale"] = ksnew, vsnew
 
     elif cfg.family == "ssm":

@@ -101,6 +101,49 @@ def test_decode_matches_full_forward(arch):
     assert max(errs) / scale < tol, errs
 
 
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8"])
+def test_decode_live_mask_leaves_dead_slots_untouched(kv_quant):
+    """decode_step(live=) writes each live slot's new K/V row (and, on the
+    int8 ring, its scales) in place at (layer, slot, position % T) and
+    nothing else: dead slots' rings and positions stay bitwise as they
+    were, and so does every other row of the live slots."""
+    cfg = get_arch("qwen3-1.7b", reduced=True).replace(
+        dtype="bfloat16", kv_cache_quant=kv_quant)
+    params = T.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+    B, Tn = 4, 16
+    cache = T.init_cache(cfg, B, Tn)
+    ks = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+    for name in ("k", "v"):
+        x = jax.random.normal(next(ks), cache[name].shape, jnp.float32)
+        cache[name] = ((x * 40).astype(jnp.int8) if kv_quant
+                       else x.astype(cache[name].dtype))
+        if kv_quant:
+            cache[name + "_scale"] = jax.random.uniform(
+                next(ks), cache[name + "_scale"].shape, jnp.float32)
+    position = jnp.array([5, 9, 21, 3], jnp.int32)   # slot 2 has wrapped
+    cache["pos"] = jnp.where(jnp.arange(Tn)[None] < position[:, None] % Tn,
+                             jnp.arange(Tn)[None], -1).astype(jnp.int32)
+    live = jnp.array([True, False, True, False])
+    before = jax.tree.map(np.asarray, cache)
+    _, after = jax.jit(lambda c: T.decode_step(
+        params, cfg, c, tokens=jnp.array([1, 2, 3, 4], jnp.int32),
+        position=position, live=live))(cache)
+    after = jax.tree.map(np.asarray, after)
+    slot = np.asarray(position) % Tn
+    written = np.zeros((B, Tn), bool)
+    written[[0, 2], slot[[0, 2]]] = True
+    for name in before:
+        changed = before[name] != after[name]
+        if name != "pos":       # (L, B, T, ...) -> which (slot, row) moved
+            changed = changed.any(axis=tuple(
+                i for i in range(changed.ndim) if i not in (1, 2)))
+        assert not (changed & ~written).any(), (
+            name, np.argwhere(changed & ~written))
+        assert changed[0, slot[0]] and changed[2, slot[2]], name
+    np.testing.assert_array_equal(after["pos"][[0, 2], slot[[0, 2]]],
+                                  np.asarray(position)[[0, 2]])
+
+
 def test_swa_ring_buffer_decode():
     """Sliding-window arch: decode far past the window with a ring cache
     must equal the full forward."""

@@ -10,15 +10,19 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import get_arch
 from repro.core.formats import WEIGHT_VARIANTS
 from repro.core.quantize import QTensor, qtensor_spec
 from repro.kernels.bfp_matmul import bfp_matmul_pallas
 from repro.kernels.prefill_attn import prefill_attn_fused
+from repro.models import transformer as T
 
 # (K, N) of every tinyllama-1.1b projection: wq/wo, wk/wv, w_gate/w_up,
 # w_down, lm_head
@@ -72,3 +76,33 @@ def test_prefill_attn_fused_compiles_for_v5e(one_chip):
              sds((B, T, KH, D), jnp.bfloat16),
              sds((B, T, KH, D), jnp.bfloat16), sds((B, C), jnp.int32),
              sds((B, T), jnp.int32))
+
+
+def test_decode_step_never_copies_the_ring_for_v5e(one_chip):
+    """The decode step writes each token's K/V into the stacked ring in
+    place and reads its layer where it lies (the ``decode_attn`` kernel):
+    at qwen3-1.7b's per-layer shapes (32 slots, a 2048-position ring, 8 kv
+    heads x 128) no instruction makes or writes a whole per-layer ring."""
+    cfg = get_arch("qwen3-1.7b").replace(n_layers=3, vocab_size=1024)
+    B, Tn = 32, 2048
+    on = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = on(jax.eval_shape(lambda: T.init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    cache = on(jax.eval_shape(lambda: T.init_cache(cfg, B, Tn)))
+    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)
+
+    def step(p, c, tok, pos, live):
+        return T.decode_step(p, cfg, c, tokens=tok, position=pos, live=live)
+
+    text = jax.jit(step, donate_argnums=1).lower(
+        params, cache, vec(jnp.int32), vec(jnp.int32),
+        vec(jnp.bool_)).compile().as_text()
+    assert re.search(r"%decode_attn[.\d]* = .*custom-call", text)
+    ring = re.compile(r"bf16\[(1,)?32,2048,8,128\]")
+    shapes = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    made = [n for n, sh in shapes.items() if ring.fullmatch(sh)]
+    assert not made, made
+    for upd in re.findall(r"dynamic-update-slice\(%\S+, %([^,)]+)", text):
+        assert not ring.fullmatch(shapes.get(upd, "")), upd
